@@ -54,7 +54,6 @@ def quick(out_path: str = "BENCH_relu.json") -> dict:
     from repro.api.plan import LAN, WAN
     from repro.core import (beaver, comm as comm_lib, costmodel, fixed, gmw,
                             gmw_ref, ring, schedule as schedule_lib, shares)
-    from repro.runtime import loop as loop_lib
 
     rng = np.random.default_rng(0)
     E = 2048
@@ -175,14 +174,14 @@ def quick(out_path: str = "BENCH_relu.json") -> dict:
     # mesh-lowered census: the same fused replay inside shard_map over a
     # party axis of size 2 must compile to exactly one collective-permute
     # per fused round with the schedule's per-round payloads (--check
-    # fails on any divergence).  Needs >= 2 devices (forced on CPU above).
+    # fails on any divergence).  Needs >= 2 devices (forced on CPU above);
+    # with one device the census is recorded as not measured (None).
     mesh_census = {"mesh_collective_permutes": None,
                    "mesh_collective_bytes": None}
-    from repro.launch.mesh import mpc_serving_mesh
-    mesh = mpc_serving_mesh()
-    if mesh.shape["party"] == 2:   # smoke fallback has no real exchange
-        from jax.experimental.shard_map import shard_map
+    if jax.device_count() >= 2:
         from jax.sharding import PartitionSpec as P
+
+        from repro.launch.mesh import make_mpc_mesh
 
         from repro.runtime.hlo_analyzer import collective_census
 
@@ -196,11 +195,11 @@ def quick(out_path: str = "BENCH_relu.json") -> dict:
 
         party = P("party")
         n_g = len(specs)
-        fused = shard_map(
-            replay, mesh=mesh,
+        fused = jax.shard_map(
+            replay, mesh=make_mpc_mesh(n_data=1),
             in_specs=([party] * n_g, [party] * n_g,
                       beaver.pool_party_specs(trs)),
-            out_specs=([party] * n_g, [party] * n_g), check_rep=False)
+            out_specs=([party] * n_g, [party] * n_g), check_vma=False)
         compiled = jax.jit(fused).lower(
             [x.lo for x in Xs], [x.hi for x in Xs], trs).compile()
         census = collective_census(compiled.as_text())
@@ -245,10 +244,11 @@ def quick(out_path: str = "BENCH_relu.json") -> dict:
     # round-loop backend it pays the whole-replay trace + XLA compile;
     # warm = every batch after, paying only dispatch + execute.  The
     # steady-state serving number (and the --check wall gate) is warm.
+    from repro.api.compile import replay_cache_stats
+    n_replays = len(replay_cache_stats())
     wall_cold = serve_mix()
     wall_warm = min(serve_mix(), serve_mix())
-    from repro.api.compile import replay_cache_stats
-    replay_entries = replay_cache_stats()
+    replay_entries = replay_cache_stats()[n_replays:]
     st = engine.stats()
     results["engine"] = {
         "mix": [list(sh) for sh in mix],
@@ -263,7 +263,7 @@ def quick(out_path: str = "BENCH_relu.json") -> dict:
         "requests_per_s": round(len(mix) / max(wall_warm, 1e-9), 3),
         "p50_sim_latency_ms": round(st["p50_sim_latency_s"] * 1e3, 3),
         "p95_sim_latency_ms": round(st["p95_sim_latency_s"] * 1e3, 3),
-        "round_loop": loop_lib.round_loop_mode(),
+        "round_loop": "scan" if replay_entries else "python",
         "wall_s": round(wall_warm, 4),
         "wall_s_cold": round(wall_cold, 4),
         "replay_trace_s": round(sum(e["trace_s"] for e in replay_entries), 4),
@@ -519,67 +519,32 @@ def transport(out_path: str = "BENCH_relu.json") -> dict:
     - requests/s through the asyncio HTTP frontend driving a
       leader/follower engine link over a second real socket pair.
 
-    Results merge into BENCH_relu.json under ``"transport"``."""
+    Results merge into BENCH_relu.json under ``"transport"``.
+
+    One process per accelerator: this process stays off JAX until the
+    JAX processes it spawns (the job writer and both parties) have exited.
+    """
     import json as json_lib
     import subprocess
     import tempfile
     import threading
     import urllib.request
 
-    import jax
     import numpy as np
 
-    from repro import api
-    from repro.configs import RESNET_SMOKE
-    from repro.core import beaver, ring
-    from repro.core.hummingbird import HBConfig, HBLayer
-    from repro.models import resnet
-    from repro.serve import Frontend, InferenceEngine
-    from repro import transport as transport_lib
+    from repro.transport.socket import free_port
 
-    rng = np.random.default_rng(0)
-    params = resnet.init(jax.random.PRNGKey(0), RESNET_SMOKE)
-
-    def afn(p, v, relu_fn=None):
-        return resnet.apply(p, v, RESNET_SMOKE, relu_fn=relu_fn)
-
-    plan = api.trace_plan(afn, params, (2, 3, 8, 8), name="smoke")
-    plan = plan.with_hb(HBConfig(
-        tuple([HBLayer(k=21, m=13)] * (plan.n_groups - 1)
-              + [HBLayer(k=13, m=13)]), plan.group_elements))
-    framed = plan.schedule().framed()
     rtt_ms = 4.0
-    predicted_latency_s = framed.latency(float("inf"), rtt_ms / 1e3)
-    # measured-wall acceptance band: schedule floor (hard physics) up to
-    # floor + per-round host budget + one-off startup (process spawn,
-    # connect handshake, jit warm-up of both parties) — see
-    # Schedule.wall_band.  Tightens with the round count, so per-round
-    # host regressions fail --check instead of hiding under the old flat
-    # 20x+120s ceiling.
-    wall_band = framed.wall_band(float("inf"), rtt_ms / 1e3)
-
-    # in-process SimComm reference: the bit-identity oracle
-    enc_model = api.compile(afn, params, RESNET_SMOKE, plan,
-                            api.Session(key=0))
-    x = rng.uniform(-0.5, 0.5, (2, 3, 8, 8)).astype(np.float32)
-    X = enc_model.encrypt(jax.random.PRNGKey(2), x)
-    pool = beaver.gen_plan_triples(jax.random.PRNGKey(3),
-                                   plan.triple_specs())
-    ref_model = api.compile(
-        afn, params, RESNET_SMOKE, plan,
-        api.Session(key=0, provider=beaver.TriplePool(pool)))
-    want = ring.to_uint64_np(
-        ref_model(X, key=jax.random.PRNGKey(4)).data)
-
+    env = dict(os.environ,
+               PYTHONPATH=os.path.join(_ROOT, "src")
+               + os.pathsep + os.environ.get("PYTHONPATH", ""))
     with tempfile.TemporaryDirectory() as tmp:
         job_dir = os.path.join(tmp, "job")
-        transport_lib.write_job(
-            job_dir, plan=plan, config="smoke", params_seed=0, infer_key=4,
-            session_seed=0, x=X, pool=pool)
-        port = transport_lib.free_port()
-        env = dict(os.environ,
-                   PYTHONPATH=os.path.join(_ROOT, "src")
-                   + os.pathsep + os.environ.get("PYTHONPATH", ""))
+        subprocess.run(
+            [sys.executable, "-c", "import sys; from repro.transport import "
+             "write_smoke_job; write_smoke_job(sys.argv[1])", job_dir],
+            env=env, cwd=_ROOT, check=True, timeout=600)
+        port = free_port()
 
         def spawn(party, *extra):
             link = (["--listen", f"127.0.0.1:{port}"] if party == 0
@@ -603,10 +568,44 @@ def transport(out_path: str = "BENCH_relu.json") -> dict:
                 outs.append((npz["lo"].copy(), npz["hi"].copy()))
             with open(os.path.join(job_dir, f"stats{p}.json")) as f:
                 stats.append(json_lib.load(f))
-        got = ring.to_uint64_np(ring.Ring64(
-            np.concatenate([outs[0][0], outs[1][0]]),
-            np.concatenate([outs[0][1], outs[1][1]])))
-        bit_identical = bool(np.array_equal(got, want))
+
+    # every JAX child has exited: this process may use JAX from here on
+    import jax
+
+    from repro import api
+    from repro.configs import RESNET_SMOKE
+    from repro.core import beaver, ring
+    from repro.models import resnet
+    from repro.serve import Frontend, InferenceEngine
+    from repro import transport as transport_lib
+
+    rng = np.random.default_rng(0)
+    params = resnet.init(jax.random.PRNGKey(0), RESNET_SMOKE)
+
+    def afn(p, v, relu_fn=None):
+        return resnet.apply(p, v, RESNET_SMOKE, relu_fn=relu_fn)
+
+    plan, X, pool = transport_lib.smoke_job_inputs()
+    framed = plan.schedule().framed()
+    predicted_latency_s = framed.latency(float("inf"), rtt_ms / 1e3)
+    # measured-wall acceptance band: schedule floor (hard physics) up to
+    # floor + per-round host budget + one-off startup (process spawn,
+    # connect handshake, jit warm-up of both parties) — see
+    # Schedule.wall_band.  Tightens with the round count, so per-round
+    # host regressions fail --check instead of hiding under the old flat
+    # 20x+120s ceiling.
+    wall_band = framed.wall_band(float("inf"), rtt_ms / 1e3)
+
+    # in-process SimComm reference of the same job: the bit-identity oracle
+    ref_model = api.compile(
+        afn, params, RESNET_SMOKE, plan,
+        api.Session(key=0, provider=beaver.TriplePool(pool)))
+    want = ring.to_uint64_np(
+        ref_model(X, key=jax.random.PRNGKey(4)).data)
+    got = ring.to_uint64_np(ring.Ring64(
+        np.concatenate([outs[0][0], outs[1][0]]),
+        np.concatenate([outs[0][1], outs[1][1]])))
+    bit_identical = bool(np.array_equal(got, want))
 
     # HTTP frontend over a leader/follower engine link on a second socket
     fport = transport_lib.free_port()
@@ -953,6 +952,10 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_relu.json",
                     help="output path for --quick / input for --check")
     args = ap.parse_args()
+    if args.transport and (args.gantt or args.quick or args.chaos):
+        # --transport spawns JAX processes; a parent that already ran JAX
+        # would hold the accelerator they need
+        ap.error("run --transport on its own (with --check at most)")
     if args.quick and ("--xla_force_host_platform_device_count"
                        not in os.environ.get("XLA_FLAGS", "")):
         # the --quick mesh-lowering census needs a party axis of size 2;
@@ -962,6 +965,8 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=2")
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.gantt:
         gantt()
     if args.quick:
@@ -979,6 +984,7 @@ def main() -> None:
     mods = [bench_comm, bench_e2e, bench_breakdown, bench_search,
             bench_accuracy, bench_roofline]
     print("name,us_per_call,derived")
+    failed = []
     for mod in mods:
         if args.filter and args.filter not in mod.__name__:
             continue
@@ -987,6 +993,9 @@ def main() -> None:
                 print(f"{name},{us:.1f},{derived}")
         except Exception as e:
             print(f"{mod.__name__}_ERROR,0,{type(e).__name__}:{e}")
+            failed.append(mod.__name__)
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

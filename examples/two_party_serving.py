@@ -17,6 +17,10 @@ Drives everything ``docs/deployment.md`` describes, in one command:
 
 ``--make-job DIR`` only writes the job directory (the two-terminal
 quickstart in docs/deployment.md starts from this) and exits.
+
+One process per accelerator: this process never runs JAX itself.  The
+job is written by a child process, and the request inputs come from
+numpy, so the party processes are free to take the device.
 """
 import argparse
 import json
@@ -30,36 +34,16 @@ import urllib.request
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "src"))
 
-import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro import api  # noqa: E402
-from repro.configs import RESNET_SMOKE  # noqa: E402
-from repro.core import beaver  # noqa: E402
-from repro.models import resnet  # noqa: E402
-from repro.transport import free_port, write_job  # noqa: E402
+from repro.transport import free_port, write_smoke_job  # noqa: E402
 
 HOST = "127.0.0.1"
 
 
 def make_job(job_dir) -> None:
     """Smoke job: traced plan + seeds + party-split shares and triples."""
-    params = resnet.init(jax.random.PRNGKey(0), RESNET_SMOKE)
-
-    def afn(p, v, relu_fn=None):
-        return resnet.apply(p, v, RESNET_SMOKE, relu_fn=relu_fn)
-
-    plan = api.trace_plan(afn, params, (2, 3, 8, 8), name="smoke")
-    plan = plan.with_hb(api.HBConfig(
-        tuple([api.HBLayer(k=21, m=13)] * (plan.n_groups - 1)
-              + [api.HBLayer(k=13, m=13)]), plan.group_elements))
-    model = api.compile(afn, params, RESNET_SMOKE, plan, api.Session(key=0))
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 3, 8, 8)) * 0.5
-    X = model.encrypt(jax.random.PRNGKey(2), x)
-    pool = beaver.gen_plan_triples(jax.random.PRNGKey(3),
-                                   plan.triple_specs())
-    write_job(job_dir, plan=plan, config="smoke", params_seed=0,
-              infer_key=4, session_seed=0, x=X, pool=pool)
+    write_smoke_job(job_dir)
     print(f"wrote job directory {job_dir}")
 
 
@@ -99,15 +83,16 @@ def main() -> int:
         return 0
 
     import tempfile
+    env = dict(os.environ, PYTHONPATH=str(ROOT / "src") + os.pathsep
+               + os.environ.get("PYTHONPATH", ""))
     tmp = None
     if args.job is None:
         tmp = tempfile.TemporaryDirectory()
         args.job = os.path.join(tmp.name, "job")
-        make_job(args.job)
+        subprocess.run([sys.executable, __file__, "--make-job", args.job],
+                       env=env, check=True)
 
     link_port, http_port = free_port(), free_port()
-    env = dict(os.environ, PYTHONPATH=str(ROOT / "src") + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
     shaping = (["--rtt-ms", str(args.rtt_ms)] if args.rtt_ms > 0 else [])
     follower = subprocess.Popen(
         [sys.executable, "-m", "repro.launch.party_host", "--party", "1",
@@ -126,8 +111,8 @@ def main() -> int:
         mix = [("alice", 10), ("bob", 11), ("alice", 12)]
         t0 = time.perf_counter()
         for tenant, seed in mix:
-            x = np.asarray(jax.random.normal(
-                jax.random.PRNGKey(seed), (2, 3, 8, 8)) * 0.5, np.float32)
+            x = (np.random.default_rng(seed).standard_normal((2, 3, 8, 8))
+                 * 0.5).astype(np.float32)
             status, resp = _http("POST", f"{base}/infer",
                                  {"tenant": tenant, "x": x.tolist()})
             assert status == 200, resp

@@ -223,13 +223,19 @@ class PrivateModel:
         cone = self.plan.cone
         if auto_batch is None:
             auto_batch = self.auto_batch
-        if (loop_lib.round_loop_mode() == "scan"
-                and loop_lib.compiled_eligible(comm)
-                and not getattr(self.plan, "opens", ())):
-            # compiled round loop: the whole replay is ONE jitted program.
-            # Plans with secret-product opens (LM attention/gating) stay on
-            # the eager loop: their key draws interleave ReLU calls with
-            # per-open draws, an order the pre-drawn payload can't express.
+        # compiled round loop: the whole replay is ONE jitted program.
+        # Plans with secret-product opens (LM attention/gating) stay on
+        # the eager loop: their key draws interleave ReLU calls with
+        # per-open draws, an order the pre-drawn payload can't express.
+        # Under an outer trace (``jax.jit(serve_step())``) the generator
+        # loop traces into the caller's program, compiled whole anyway.
+        # The replay's size is public metadata (shapes), not share data.
+        traced = any(isinstance(t.data.lo, jax.core.Tracer) for t in tensors)
+        compiled = (not traced and loop_lib.compiled_eligible(comm)
+                    and not getattr(self.plan, "opens", ())
+                    and loop_lib.round_loop_mode(lambda: self._relu_calls(
+                        tensors, auto_batch, params)) == "scan")
+        if compiled:  # hbcheck: disable=R003
             return self._run_streams_compiled(tensors, key_iters, providers,
                                               comm, params, auto_batch)
 
@@ -271,7 +277,8 @@ class PrivateModel:
         return (auto_batch,) + tuple(
             (tuple(t.shape), t.frac_bits) for t in tensors)
 
-    def _relu_layout(self, tensors: Sequence[MPCTensor], auto_batch: bool):
+    def _relu_layout(self, tensors: Sequence[MPCTensor], auto_batch: bool,
+                     params):
         """Per-ReLU-call (group, per-stream element counts) of one replay,
         in call order — recorded from an abstract (``jax.eval_shape``)
         pass of the forward, so the model is never executed.  This is what
@@ -291,9 +298,17 @@ class PrivateModel:
             jax.eval_shape(
                 lambda p, ts: self.mpc_forward(p, list(ts), self.cfg,
                                                relu_rec, stub),
-                self.params, tuple(tensors))
+                params, tuple(tensors))
             self._layout_cache[sig] = tuple(records)
         return self._layout_cache[sig]
+
+    def _relu_calls(self, tensors: Sequence[MPCTensor], auto_batch: bool,
+                    params) -> int:
+        """ReLU calls of one replay summed over its live sibling streams:
+        the size of the program the compiled round loop would unroll."""
+        return sum(sum(1 for n in ns if n)
+                   for g, ns in self._relu_layout(tensors, auto_batch, params)
+                   if not self.plan.hb.layers[g].is_identity)
 
     def _plan_digest(self) -> str:
         if "digest" not in self._layout_cache:
@@ -380,7 +395,7 @@ class PrivateModel:
         The caller's ``CoalescingComm`` counters advance by the traced
         round timeline, keeping measured-vs-schedule accounting intact.
         """
-        layout = self._relu_layout(tensors, auto_batch)
+        layout = self._relu_layout(tensors, auto_batch, params)
         hb_layers = self.plan.hb.layers
         cone = self.plan.cone
         payload = []
@@ -476,7 +491,6 @@ class PrivateModel:
 
             return step
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         if party_axis not in mesh.axis_names:
@@ -506,12 +520,12 @@ class PrivateModel:
             share = (PartitionSpec(party_axis, data_axis) if data_axis
                      else PartitionSpec(party_axis))
             rep = PartitionSpec()
-            fused = shard_map(
+            fused = jax.shard_map(
                 _replay, mesh=mesh,
                 in_specs=(rep, share, share,
                           beaver.pool_party_specs(triples, party_axis,
                                                   data_axis=data_axis), rep),
-                out_specs=(share, share), check_rep=False)
+                out_specs=(share, share), check_vma=False)
             return fused(params, lo, hi, triples, key)
 
         return step

@@ -282,7 +282,16 @@ def _a2b_prepare_rounds(key, v_packed: jax.Array, comm):
     # masks are therefore bit-identical across backends by construction.
     full = jax.random.bits(key, (comm.n_parties,) + v_packed.shape[1:],
                            dtype=_U32)
+    # The mask is data-independent, so a compiler may hoist its exchange
+    # ahead of earlier rounds.  XOR-ing in ``s & ~s`` (always 0, not folded
+    # by XLA) makes it depend on the value it masks: the compiled program
+    # keeps the schedule's round order.  ``lax.optimization_barrier`` does
+    # not: XLA removes it before scheduling, and the exchange is hoisted
+    # again (the HLO census tests catch either regression).
     r = comm.party_slice(full)
+    if v_packed.size:
+        s = v_packed.reshape(-1)[0]
+        r = r ^ (s & ~s)
     masked = v_packed ^ r
     other_mask = yield r
     p0 = comm.party_is(0, v_packed)

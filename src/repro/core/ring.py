@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 _U32 = jnp.uint32
-_MASK16 = jnp.uint32(0xFFFF)
+_MASK16 = np.uint32(0xFFFF)   # numpy: importing must not touch a device
 
 
 @jax.tree_util.register_pytree_node_class

@@ -8,8 +8,9 @@ exchange.
 
 TPU mapping: each grid step loads a (BW, 32) tile of values into VMEM,
 emits a (w, BW) tile of packed words.  The shift/mask ladder runs on the
-VPU; w is a compile-time constant (k - m from the HummingBird config), so
-the plane loop fully unrolls.  Lane-dim tiles are multiples of 128.
+VPU.  Packing loops over the w planes (k - m from the HummingBird config)
+with ``fori_loop``: unrolled, a 64-plane pack takes the TPU compiler over
+a minute.  Lane-dim tiles are multiples of 128.
 """
 from __future__ import annotations
 
@@ -26,9 +27,17 @@ BLOCK_WORDS = 256  # words per grid step; 256*32 = 8192 elements per tile
 def _pack_kernel(v_ref, out_ref, *, w: int):
     v = v_ref[...]                                    # (BW, 32) uint32
     shifts = jnp.arange(32, dtype=_U32)[None, :]      # bit position per lane
-    for i in range(w):
-        bits = (v >> _U32(i)) & _U32(1)
-        out_ref[i, :] = (bits << shifts).sum(axis=-1, dtype=_U32)
+
+    def plane(i, carry):
+        bits = (v >> i.astype(_U32)) & _U32(1)
+        # the 32 lane bits are disjoint, so their int32 sum is their OR
+        # (Mosaic has no unsigned reductions); bitcast back to uint32
+        word = jax.lax.bitcast_convert_type(bits << shifts, jnp.int32)
+        out_ref[pl.ds(i, 1), :] = jax.lax.bitcast_convert_type(
+            word.sum(axis=-1, dtype=jnp.int32), _U32)[None, :]
+        return carry
+
+    jax.lax.fori_loop(0, w, plane, 0)
 
 
 def _unpack_kernel(words_ref, out_ref, *, w: int):

@@ -65,7 +65,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                 "status": "skipped", "reason": reason}
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         args, kwargs = specs_lib.input_specs(cfg, shape, mesh)
         fn = _step_fn(cfg, shape)
         lowered = jax.jit(fn).lower(*args, **kwargs)

@@ -6,16 +6,26 @@ module never touches jax device state; the dry-run sets
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the protocol code
+    reshapes sharded share buffers freely (``CoalescingComm.flush``), which
+    the ``Explicit`` axes ``make_mesh`` defaults to would refuse."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def mpc_mesh_shape(n_devices: Optional[int] = None) -> Tuple[int, int]:
@@ -43,28 +53,17 @@ def make_mpc_mesh(n_data: Optional[int] = None):
     topology yields 2 x 256)."""
     if n_data is None:
         _, n_data = mpc_mesh_shape()
-    return jax.make_mesh((2, n_data), ("party", "data"),
-                         devices=jax.devices()[: 2 * n_data])
+    return _auto_mesh((2, n_data), ("party", "data"),
+                      devices=jax.devices()[: 2 * n_data])
 
 
 def make_mpc_smoke_mesh():
     """1-device MPC mesh with the serving axis names (CPU smoke tests:
     both party shards land on the same device, shardings still resolve,
     and the mesh-native serve path degenerates to local exchanges)."""
-    return jax.make_mesh((1, 1), ("party", "data"))
-
-
-def mpc_serving_mesh():
-    """Best MPC mesh the current topology supports: the full
-    ``make_mpc_mesh`` (party axis size 2 — one device slice per
-    non-colluding server, protocol exchanges are real collectives) when at
-    least two devices exist, else the 1-device smoke mesh (party axis size
-    1 — exchanges stay local).  Entry point for serving scripts and the
-    quick benchmark's mesh-lowering census."""
-    return (make_mpc_mesh() if jax.device_count() >= 2
-            else make_mpc_smoke_mesh())
+    return _auto_mesh((1, 1), ("party", "data"))
 
 
 def make_smoke_mesh():
     """1-device mesh with the production axis names (CPU smoke tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

@@ -54,6 +54,7 @@ from repro import api, errors
 from repro.checkpoint import store
 from repro.core import beaver, comm as comm_lib, faults as faults_lib
 from repro.models import resnet
+from repro.runtime.compile_cache import enable_compile_cache
 from repro import transport
 from repro.transport.socket import parse_address
 
@@ -125,6 +126,7 @@ def run(argv=None) -> int:
     if (args.listen is None) == (args.peer is None):
         print("pass exactly one of --listen / --peer", file=sys.stderr)
         return 2
+    enable_compile_cache()
     job = (transport.load_party(args.job, args.party) if not args.follow
            else transport.load_job(args.job))
     cfg, plan = job["cfg"], job["plan"]

@@ -22,19 +22,10 @@ from jax.sharding import PartitionSpec as P
 
 
 def context_mesh():
-    try:
-        import jax._src.mesh as mesh_lib  # jax 0.8: `with mesh:` resources
-        env = mesh_lib.thread_resources.env.physical_mesh
-        if not env.empty:
-            return env
-    except Exception:
-        pass
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
+    """The mesh set by ``jax.set_mesh``, or None outside one."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is not None and m.axis_names:
+        return m
     return None
 
 

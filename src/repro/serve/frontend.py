@@ -313,10 +313,13 @@ def main(argv=None) -> int:
     from repro import transport
     from repro.transport.socket import parse_address
 
+    from repro.runtime.compile_cache import enable_compile_cache
+
     args = build_parser().parse_args(argv)
     if (args.listen is None) == (args.peer is None):
         print("pass exactly one of --listen / --peer", file=sys.stderr)
         return 2
+    enable_compile_cache()
     job = transport.load_job(args.job)
     engine, link = build_engine(args, job)
     frontend = Frontend(engine)

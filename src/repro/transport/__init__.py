@@ -18,11 +18,11 @@ repro.launch.party_host``, and serve requests through
 from .socket import (HEADER, LinkShaper, SocketComm, free_port,
                      parse_address)
 from .job import load_job, load_party, pool_treedef, resolve_config, \
-    write_job
+    smoke_job_inputs, write_job, write_smoke_job
 from .engine_link import EngineLink, serve_follower, tenant_provider_factory
 
 __all__ = [
     "HEADER", "LinkShaper", "SocketComm", "free_port", "parse_address",
     "load_job", "load_party", "pool_treedef", "resolve_config",
-    "write_job", "EngineLink", "serve_follower", "tenant_provider_factory",
+    "smoke_job_inputs", "write_job", "write_smoke_job", "EngineLink", "serve_follower", "tenant_provider_factory",
 ]

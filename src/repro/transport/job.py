@@ -119,3 +119,37 @@ def load_party(job_dir, party: int) -> Dict:
                 job["pool"] = jax.tree_util.tree_unflatten(
                     pool_treedef(job["plan"]), leaves)
     return job
+
+
+def smoke_job_inputs(seed: int = 0):
+    """``(plan, X, pool)`` of the smoke deployment job, all from ``seed``:
+    ``RESNET_SMOKE`` weights (``PRNGKey(seed)``, as ``params_seed``
+    re-derives them), 8-bit windows with the last group culled, one
+    (2, 3, 8, 8) secret-shared input, and its offline triple pool.
+    Deterministic, so a caller can rebuild the job's inputs for a
+    reference run without reading the job back."""
+    from repro import api
+    from repro.models import resnet
+
+    params = resnet.init(jax.random.PRNGKey(seed), RESNET_SMOKE)
+
+    def afn(p, v, relu_fn=None):
+        return resnet.apply(p, v, RESNET_SMOKE, relu_fn=relu_fn)
+
+    plan = api.trace_plan(afn, params, (2, 3, 8, 8), name="smoke")
+    plan = plan.with_hb(api.HBConfig(
+        tuple([api.HBLayer(k=21, m=13)] * (plan.n_groups - 1)
+              + [api.HBLayer(k=13, m=13)]), plan.group_elements))
+    k_x, k_share, k_pool = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    x = jax.random.normal(k_x, (2, 3, 8, 8)) * 0.5
+    X = MPCTensor.from_plain(k_share, x)
+    pool = beaver.gen_plan_triples(k_pool, plan.triple_specs())
+    return plan, X, pool
+
+
+def write_smoke_job(job_dir, seed: int = 0) -> pathlib.Path:
+    """Write the smoke job (``smoke_job_inputs(seed)``); the protocol key
+    is ``PRNGKey(seed + 4)``."""
+    plan, X, pool = smoke_job_inputs(seed)
+    return write_job(job_dir, plan=plan, config="smoke", params_seed=seed,
+                     infer_key=seed + 4, session_seed=seed, x=X, pool=pool)

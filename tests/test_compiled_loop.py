@@ -186,6 +186,23 @@ def test_round_loop_mode_env(monkeypatch):
     assert loop_lib.round_loop_mode() == "scan"        # invalid -> default
 
 
+@pytest.mark.parametrize("calls,auto", [
+    (0, "scan"),
+    (loop_lib.MAX_COMPILED_RELU_CALLS, "scan"),
+    (loop_lib.MAX_COMPILED_RELU_CALLS + 1, "python"),
+])
+def test_round_loop_mode_by_replay_size(monkeypatch, calls, auto):
+    monkeypatch.delenv("HB_ROUND_LOOP", raising=False)
+    assert loop_lib.round_loop_mode(lambda: calls) == auto
+
+    def untraced():
+        raise AssertionError("size asked for under an override")
+
+    for forced in ("scan", "python"):          # the env var overrides
+        monkeypatch.setenv("HB_ROUND_LOOP", forced)
+        assert loop_lib.round_loop_mode(untraced) == forced
+
+
 def test_compiled_eligible_exact_types():
     assert loop_lib.compiled_eligible(comm_lib.SimComm())
     assert loop_lib.compiled_eligible(
@@ -296,3 +313,64 @@ def test_ineligible_comm_stays_on_generator_loop(tiny_model, monkeypatch):
     np.testing.assert_array_equal(np.asarray(out.data.lo),
                                   np.asarray(out_py.data.lo))
     assert cc.n_rounds == cc_py.n_rounds
+
+
+def test_large_replay_takes_generator_loop(tiny_model, monkeypatch):
+    """With HB_ROUND_LOOP unset, a replay of more ReLU calls (over its
+    sibling streams) than ``MAX_COMPILED_RELU_CALLS`` runs on the
+    generator loop: no replay is compiled, outputs and counters match
+    the forced generator run.  Up to the limit it is compiled."""
+    from repro.api.compile import replay_cache_stats
+    params, plan = tiny_model
+    xs = [MPCTensor.from_plain(jax.random.PRNGKey(30 + i),
+                               jax.random.normal(jax.random.PRNGKey(40 + i),
+                                                 (2, D_IN)))
+          for i in range(3)]
+    model = api.compile(loop_apply, params, LoopCfg(), plan,
+                        api.Session(key=0))
+    assert model._relu_calls(xs[:1], True, params) == 2
+    assert model._relu_calls(xs, True, params) == 6
+    monkeypatch.setattr(loop_lib, "MAX_COMPILED_RELU_CALLS", 5)
+    monkeypatch.setenv("HB_XLA_OPT", "0")
+    monkeypatch.delenv("HB_ROUND_LOOP", raising=False)
+    cc = comm_lib.CoalescingComm(comm_lib.SimComm())
+    model = api.compile(loop_apply, params, LoopCfg(), plan,
+                        api.Session(key=0, comm=cc))
+    n_entries = len(replay_cache_stats())
+    outs = model(xs, key=jax.random.PRNGKey(5))
+    assert len(replay_cache_stats()) == n_entries
+    monkeypatch.setenv("HB_ROUND_LOOP", "python")
+    cc_py = comm_lib.CoalescingComm(comm_lib.SimComm())
+    ref = api.compile(loop_apply, params, LoopCfg(), plan,
+                      api.Session(key=0, comm=cc_py))(
+        xs, key=jax.random.PRNGKey(5))
+    for a, b in zip(outs, ref):
+        np.testing.assert_array_equal(np.asarray(a.data.lo),
+                                      np.asarray(b.data.lo))
+        np.testing.assert_array_equal(np.asarray(a.data.hi),
+                                      np.asarray(b.data.hi))
+    assert cc.round_bytes == cc_py.round_bytes
+    monkeypatch.delenv("HB_ROUND_LOOP")
+    model(xs[:2], key=jax.random.PRNGKey(5))    # 4 calls: compiled
+    assert model.replay_stats(xs[:2]) is not None
+
+
+def test_traced_replay_stays_on_generator_loop(tiny_model, monkeypatch):
+    """Under an outer ``jax.jit`` the replay traces into the caller's
+    program (a compiled replay cannot take tracers), bit-identical to
+    the eager call, which runs the compiled replay."""
+    params, plan = tiny_model
+    monkeypatch.setenv("HB_ROUND_LOOP", "scan")
+    monkeypatch.setenv("HB_XLA_OPT", "0")
+    model = api.compile(loop_apply, params, LoopCfg(), plan,
+                        api.Session(key=0))
+    X = MPCTensor.from_plain(jax.random.PRNGKey(50),
+                             jax.random.normal(jax.random.PRNGKey(51),
+                                               (2, D_IN)))
+    step = model.serve_step()
+    key = jax.random.PRNGKey(52)
+    traced = jax.jit(step)(params, X.data.lo, X.data.hi, None, key)
+    eager = step(params, X.data.lo, X.data.hi, None, key)
+    assert model.replay_stats([X]) is not None
+    for a, b in zip(traced, eager):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -514,6 +514,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro import api
 from repro.core import MPCTensor, beaver, ring, schedule as schedule_lib
@@ -547,10 +548,12 @@ X = MPCTensor.from_plain(jax.random.PRNGKey(3), x)
 pool = beaver.gen_plan_triples(jax.random.PRNGKey(4), plan.triple_specs())
 key = jax.random.PRNGKey(5)
 
-mesh = jax.make_mesh((2, 2), ("party", "data"))
+mesh = jax.make_mesh((2, 2), ("party", "data"),
+                     axis_types=(AxisType.Auto,) * 2)
 
 # unsharded two-party reference census
 ref_step = model.serve_step(jax.make_mesh((2,), ("party",),
+                                          axis_types=(AxisType.Auto,),
                                           devices=jax.devices()[:2]))
 ref = collective_census(jax.jit(ref_step).lower(
     params, X.data.lo, X.data.hi, pool, key).compile().as_text())

@@ -120,7 +120,6 @@ def test_ops_knob_flip_retraces_not_stale(monkeypatch, rng):
     """ref path, then HB_PALLAS_INTERPRET=1, then a BLOCK_WORDS override:
     three traces of the same public wrapper in one process, all
     bit-identical — the knobs are static jit args, not baked-in globals."""
-    monkeypatch.delenv("REPRO_FORCE_PALLAS_INTERPRET", raising=False)
     monkeypatch.delenv("HB_PALLAS_INTERPRET", raising=False)
     monkeypatch.delenv("HB_BLOCK_WORDS", raising=False)
     w, words, shift = 8, 256, 2
@@ -162,3 +161,17 @@ def test_ops_noninterpret_knob(monkeypatch, rng):
     d_r, e_r = ref.ks_mask(g, p, a, b, 2)
     np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(d_r))
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(e_r))
+
+
+@pytest.mark.parametrize("shape", [(2, 8, 300), (2, 128, 512)])
+def test_ops_beaver_and_party_plane_words_interpret(monkeypatch, rng, shape):
+    """The protocol hands ``ops.beaver_and`` (party, planes, words)
+    operands; the Pallas path folds them onto the kernel's 2-D tiling and
+    stays bit-identical to the ref path."""
+    monkeypatch.setenv("HB_PALLAS_INTERPRET", "1")
+    d, e, a, b, c = (_mk(rng, shape) for _ in range(5))
+    sel = jnp.where(jnp.arange(2)[:, None, None] == 0,
+                    jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+    got = ops.beaver_and(d, e, a, b, c, sel)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(ref.beaver_and(d, e, a, b, c, sel)))

@@ -24,14 +24,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import api
 from repro.configs import RESNET_SMOKE
 from repro.core import beaver, comm as comm_lib, fixed, gmw, ring, shares
 from repro.core.hummingbird import HBConfig, HBLayer
-from repro.launch.mesh import make_mpc_smoke_mesh
+from repro.launch.mesh import make_mpc_smoke_mesh, make_smoke_mesh
 from repro.models import resnet
 
 
@@ -43,9 +42,9 @@ from repro.models import resnet
 def _smoke_shard_map(fn, n_out: int = 1):
     mesh = make_mpc_smoke_mesh()
     spec = P("party")
-    return shard_map(fn, mesh=mesh, in_specs=spec,
-                     out_specs=(spec,) * n_out if n_out > 1 else spec,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec,
+                         out_specs=(spec,) * n_out if n_out > 1 else spec,
+                         check_vma=False)
 
 
 def test_meshcomm_swap_matches_simcomm_on_smoke_mesh():
@@ -92,9 +91,10 @@ def test_coalescing_flush_over_meshcomm_bit_identical_to_sim():
 
     want = run(comm_lib.SimComm)(*payloads)
     mesh = make_mpc_smoke_mesh()
-    got = shard_map(run(lambda: comm_lib.MeshComm("party", 1)), mesh=mesh,
-                    in_specs=(P("party"),) * 3, out_specs=(P("party"),) * 3,
-                    check_rep=False)(*payloads)
+    got = jax.shard_map(run(lambda: comm_lib.MeshComm("party", 1)),
+                        mesh=mesh, in_specs=(P("party"),) * 3,
+                        out_specs=(P("party"),) * 3,
+                        check_vma=False)(*payloads)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
@@ -151,7 +151,7 @@ def test_mesh_serve_step_requires_triple_pool():
 def test_mesh_serve_step_rejects_party_axis_free_mesh():
     model = _smoke_model()[0]
     with pytest.raises(ValueError, match="party"):
-        model.serve_step(jax.make_mesh((1, 1), ("data", "model")))
+        model.serve_step(make_smoke_mesh())
 
 
 # ---------------------------------------------------------------------------
@@ -165,8 +165,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro import api
 from repro.configs import RESNET_SMOKE
@@ -191,7 +190,7 @@ for cone in (False, True):
         trs.append(beaver.gen_relu_triples(jax.random.PRNGKey(60 + i), n,
                                            k - m, cone=cone))
     kms = [(k, m) for _, k, m in specs]
-    mesh = jax.make_mesh((2,), ("party",))
+    mesh = jax.make_mesh((2,), ("party",), axis_types=(AxisType.Auto,))
 
     def replay(lo_list, hi_list, triples):
         cc = comm_lib.CoalescingComm(comm_lib.MeshComm("party", 2))
@@ -201,11 +200,11 @@ for cone in (False, True):
 
     party = P("party")
     n_g = len(specs)
-    fused = shard_map(replay, mesh=mesh,
-                      in_specs=([party] * n_g, [party] * n_g,
-                                beaver.pool_party_specs(trs)),
-                      out_specs=([party] * n_g, [party] * n_g),
-                      check_rep=False)
+    fused = jax.shard_map(replay, mesh=mesh,
+                          in_specs=([party] * n_g, [party] * n_g,
+                                    beaver.pool_party_specs(trs)),
+                          out_specs=([party] * n_g, [party] * n_g),
+                          check_vma=False)
     compiled = jax.jit(fused).lower([x.lo for x in Xs], [x.hi for x in Xs],
                                     trs).compile()
     census = collective_census(compiled.as_text())

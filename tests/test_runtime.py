@@ -6,6 +6,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import beaver, comm as comm_lib, costmodel, gmw, ring, shares
+from repro.launch.mesh import make_smoke_mesh
 from repro.runtime import sharding as sh
 from repro.runtime.hlo_analyzer import analyze, normalize_cost_analysis
 
@@ -14,7 +15,7 @@ pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_smoke_mesh()
 
 
 def test_analyzer_scan_equals_unroll():
@@ -81,7 +82,7 @@ def test_costmodel_validated_against_hlo_collectives():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import jax, jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 from repro.core import beaver, comm as comm_lib, costmodel, gmw, ring
 from repro.runtime.hlo_analyzer import analyze
 
@@ -92,7 +93,7 @@ def step(lo, hi, tr):
     out = gmw.relu(jax.random.PRNGKey(0), ring.Ring64(lo, hi), tr, cm, k=8, m=0)
     return out.lo, out.hi
 
-mesh = jax.make_mesh((2,), ("party",))
+mesh = jax.make_mesh((2,), ("party",), axis_types=(AxisType.Auto,))
 tr = beaver.gen_relu_triples(jax.random.PRNGKey(1), E, w)
 shp = NamedSharding(mesh, P("party"))
 lo = jax.ShapeDtypeStruct((2, E), jnp.uint32, sharding=shp)
