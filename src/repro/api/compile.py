@@ -23,6 +23,7 @@ from repro import errors
 from repro.core import beaver, comm as comm_lib, ring
 from repro.core.mpc_tensor import MPCTensor, products_many, relu_many
 from repro.runtime import loop as loop_lib
+from repro.runtime.spans import span
 from .plan import Plan
 from .session import Session
 
@@ -240,23 +241,26 @@ class PrivateModel:
                                               comm, params, auto_batch)
 
         def _relu(hs: List[MPCTensor], g: int) -> List[MPCTensor]:
-            hb = hb_layers[g]
-            keys = [next(key_iters[i]) for i in range(len(hs))]
-            tris = [providers[i].relu_triples(math.prod(h.shape), hb.width,
-                                              cone=cone)
-                    for i, h in enumerate(hs)]
-            outs = list(hs)
-            # zero-element streams (empty batch) have nothing to compute
-            live = [i for i, h in enumerate(hs) if math.prod(h.shape)]
-            if live:
-                rets = relu_many([keys[i] for i in live],
-                                 [hs[i] for i in live],
-                                 comm=comm, hbs=[hb] * len(live),
-                                 triples_list=[tris[i] for i in live],
-                                 cone=cone, auto_batch=auto_batch)
-                for j, i in enumerate(live):
-                    outs[i] = rets[j]
-            return outs
+            with span("relu.layer", group=g,
+                      elements=sum(math.prod(h.shape) for h in hs)):
+                hb = hb_layers[g]
+                keys = [next(key_iters[i]) for i in range(len(hs))]
+                with span("beaver.triples"):
+                    tris = [providers[i].relu_triples(math.prod(h.shape),
+                                                      hb.width, cone=cone)
+                            for i, h in enumerate(hs)]
+                outs = list(hs)
+                # zero-element streams (empty batch) have nothing to compute
+                live = [i for i, h in enumerate(hs) if math.prod(h.shape)]
+                if live:
+                    rets = relu_many([keys[i] for i in live],
+                                     [hs[i] for i in live],
+                                     comm=comm, hbs=[hb] * len(live),
+                                     triples_list=[tris[i] for i in live],
+                                     cone=cone, auto_batch=auto_batch)
+                    for j, i in enumerate(live):
+                        outs[i] = rets[j]
+                return outs
 
         # Secret-product hooks (see Plan.opens): stream i draws ONE key per
         # product site — independent of how many sibling streams run — and
@@ -359,12 +363,12 @@ class PrivateModel:
                 live = [i for i, h in enumerate(hs) if math.prod(h.shape)]
                 if live:
                     hb = hb_layers[g]
-                    rets = relu_many([keys[i] for i in live],
-                                     [hs[i] for i in live],
-                                     comm=cc, hbs=[hb] * len(live),
-                                     triples_list=[tris[i] for i in live],
-                                     cone=cone, auto_batch=auto_batch,
-                                     loop="scan")
+                    with jax.named_scope("relu.layer"):
+                        rets = relu_many(
+                            [keys[i] for i in live], [hs[i] for i in live],
+                            comm=cc, hbs=[hb] * len(live),
+                            triples_list=[tris[i] for i in live], cone=cone,
+                            auto_batch=auto_batch, loop="scan")
                     for j, i in enumerate(live):
                         outs[i] = rets[j]
                 return outs
@@ -402,8 +406,10 @@ class PrivateModel:
         for g, ns in layout:
             hb = hb_layers[g]
             keys = tuple(next(key_iters[i]) for i in range(len(tensors)))
-            tris = tuple(providers[i].relu_triples(ns[i], hb.width, cone=cone)
-                         for i in range(len(tensors)))
+            with span("beaver.triples"):
+                tris = tuple(providers[i].relu_triples(ns[i], hb.width,
+                                                       cone=cone)
+                             for i in range(len(tensors)))
             payload.append((keys, tris))
         entry = self._compiled_replay(self._stream_sig(tensors, auto_batch),
                                       auto_batch, params, tuple(tensors),

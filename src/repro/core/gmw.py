@@ -46,6 +46,8 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.runtime.spans import span
+
 from . import beaver, comm as comm_lib, ring, ring_linalg, \
     schedule as schedule_lib, shares
 from .schedule import cone_sets  # noqa: F401  (canonical home: core.schedule)
@@ -58,11 +60,22 @@ _U32 = jnp.uint32
 # ---------------------------------------------------------------------------
 
 def drive(gen, comm):
-    """Run one round generator to completion: one ``comm.swap`` per round."""
+    """Run one round generator to completion: one ``comm.swap`` per round.
+
+    Host spans: ``round.local`` around each step of the generator (the
+    local work that makes round ``r``'s payload; for the step that
+    returns, ``r`` is one past the last round) and ``round.exchange``
+    around each swap."""
+    r = 0
     try:
-        payload = gen.send(None)
+        with span("round.local", round=r):
+            payload = gen.send(None)
         while True:
-            payload = gen.send(comm.swap(payload))
+            with span("round.exchange", round=r):
+                opened = comm.swap(payload)
+            r += 1
+            with span("round.local", round=r):
+                payload = gen.send(opened)
     except StopIteration as e:
         return e.value
 
@@ -80,6 +93,11 @@ def run_streams(comm, streams: Sequence, on_round=None) -> List:
     exchange.  This is the snapshot/watchdog seam: a
     ``JournaledComm.snapshot`` here makes the execution resumable from
     round ``r``, and the serving engine hangs straggler detection off it.
+
+    Host spans: ``round.local`` around each stream's step (the local work
+    that makes its round-``r`` payload; for the step that returns, ``r``
+    is one past the stream's last round) and ``round.exchange`` around
+    each round's enqueue and flush.
     """
     cc = (comm if isinstance(comm, comm_lib.CoalescingComm)
           else comm_lib.CoalescingComm(comm))
@@ -87,17 +105,21 @@ def run_streams(comm, streams: Sequence, on_round=None) -> List:
     live = {}
     for i, s in enumerate(streams):
         try:
-            live[i] = (s, s.send(None))
+            with span("round.local", round=0):
+                live[i] = (s, s.send(None))
         except StopIteration as e:  # zero-round stream
             results[i] = e.value
     r = 0
     while live:
-        handles = {i: cc.enqueue(payload) for i, (_, payload) in live.items()}
-        opened = cc.flush()
+        with span("round.exchange", round=r):
+            handles = {i: cc.enqueue(payload)
+                       for i, (_, payload) in live.items()}
+            opened = cc.flush()
         nxt = {}
         for i, (s, _) in live.items():
             try:
-                nxt[i] = (s, s.send(opened[handles[i]]))
+                with span("round.local", round=r + 1):
+                    nxt[i] = (s, s.send(opened[handles[i]]))
             except StopIteration as e:
                 results[i] = e.value
         live = nxt
@@ -121,14 +143,18 @@ def _and_open_rounds(x, y, triple: beaver.BinTriple, comm):
     """Round generator for z = x & y on XOR-shared packed words."""
     from repro.kernels import ops as kops  # lazy: kernels import core.ring
 
-    d = x ^ triple.a
-    e = y ^ triple.b
-    opened = yield jnp.stack([d, e], axis=1)  # single exchange
-    d_open = d ^ opened[:, 0]
-    e_open = e ^ opened[:, 1]
-    sel = _sel_mask(comm, x)
-    # local evaluation fused in one VMEM pass (kernels/gmw_round.py)
-    return kops.beaver_and(d_open, e_open, triple.a, triple.b, triple.c, sel)
+    with span("relu.adder"):
+        d = x ^ triple.a
+        e = y ^ triple.b
+        payload = jnp.stack([d, e], axis=1)
+    opened = yield payload                    # single exchange
+    with span("relu.adder"):
+        d_open = d ^ opened[:, 0]
+        e_open = e ^ opened[:, 1]
+        sel = _sel_mask(comm, x)
+        # local evaluation fused in one VMEM pass (kernels/gmw_round.py)
+        return kops.beaver_and(d_open, e_open, triple.a, triple.b, triple.c,
+                               sel)
 
 
 def and_open(x, y, triple: beaver.BinTriple, comm) -> jax.Array:
@@ -152,50 +178,63 @@ def _adder_msb_rounds(xw, yw, triples: beaver.ReluTriples, comm, w: int,
     """
     from repro.kernels import ops as kops
 
-    p0 = xw ^ yw                      # initial propagate (local)
-    if w == 1:
-        return p0[..., 0, :]
+    with span("relu.adder"):
+        p0 = xw ^ yw                  # initial propagate (local)
+        if w == 1:
+            return p0[..., 0, :]
     L = beaver.n_levels(w)
     if not cone:
         g = yield from _and_open_rounds(xw, yw, triples.bin_init, comm)
         p = p0
-        sel = _sel_mask(comm, xw)
+        with span("relu.adder"):
+            sel = _sel_mask(comm, xw)
         for lvl in range(L):
             d = 1 << lvl
-            tri = jax.tree_util.tree_map(lambda t: t[lvl], triples.bin_levels)
-            # fused: shift + lhs/rhs build + triple masking, one pass
-            d_half, e_half = kops.ks_mask(g, p, tri.a, tri.b, d)
-            opened = yield jnp.stack([d_half, e_half], axis=1)  # one round
-            # fused: opening XOR + Beaver eval + level combine, one pass
-            g, p = kops.ks_combine(d_half, opened[:, 0], e_half, opened[:, 1],
-                                   tri.a, tri.b, tri.c, sel, g)
+            with span("relu.adder"):
+                tri = jax.tree_util.tree_map(lambda t: t[lvl],
+                                             triples.bin_levels)
+                # fused: shift + lhs/rhs build + triple masking, one pass
+                d_half, e_half = kops.ks_mask(g, p, tri.a, tri.b, d)
+                payload = jnp.stack([d_half, e_half], axis=1)
+            opened = yield payload                              # one round
+            with span("relu.adder"):
+                # fused: opening XOR + Beaver eval + level combine, one pass
+                g, p = kops.ks_combine(d_half, opened[:, 0], e_half,
+                                       opened[:, 1], tri.a, tri.b, tri.c, sel,
+                                       g)
         # carry into bit (w-1) is prefix-generate of bit (w-2)
-        return p0[..., w - 1, :] ^ g[..., w - 2, :]
+        with span("relu.adder"):
+            return p0[..., w - 1, :] ^ g[..., w - 2, :]
 
     init_pos, level_sets = cone_sets(w)
     # static cone layout: dense sub-plane tensors per level, positions are
     # Python-side metadata (g_map/p_map) resolved entirely at trace time
-    g_sub = yield from _and_open_rounds(
-        jnp.stack([xw[..., i, :] for i in init_pos], axis=-2),
-        jnp.stack([yw[..., i, :] for i in init_pos], axis=-2),
-        triples.bin_init, comm)
-    g_map = {i: g_sub[..., j, :] for j, i in enumerate(init_pos)}
-    p_map = {i: p0[..., i, :] for i in range(w)}
+    with span("relu.adder"):
+        x_init = jnp.stack([xw[..., i, :] for i in init_pos], axis=-2)
+        y_init = jnp.stack([yw[..., i, :] for i in init_pos], axis=-2)
+    g_sub = yield from _and_open_rounds(x_init, y_init, triples.bin_init,
+                                        comm)
+    with span("relu.adder"):
+        g_map = {i: g_sub[..., j, :] for j, i in enumerate(init_pos)}
+        p_map = {i: p0[..., i, :] for i in range(w)}
     for lvl in range(L):
         d = 1 << lvl
         pos = level_sets[lvl]
         if not pos:
             continue
         n = len(pos)
-        lhs = jnp.stack([p_map[i] for i in pos] * 2, axis=-2)
-        rhs = jnp.stack([g_map[i - d] for i in pos] +
-                        [p_map[i - d] for i in pos], axis=-2)
+        with span("relu.adder"):
+            lhs = jnp.stack([p_map[i] for i in pos] * 2, axis=-2)
+            rhs = jnp.stack([g_map[i - d] for i in pos] +
+                            [p_map[i - d] for i in pos], axis=-2)
         out = yield from _and_open_rounds(lhs, rhs, triples.bin_levels[lvl],
                                           comm)                # one round
-        for j, i in enumerate(pos):
-            g_map[i] = g_map[i] ^ out[..., j, :]
-            p_map[i] = out[..., n + j, :]
-    return p0[..., w - 1, :] ^ g_map[w - 2]
+        with span("relu.adder"):
+            for j, i in enumerate(pos):
+                g_map[i] = g_map[i] ^ out[..., j, :]
+                p_map[i] = out[..., n + j, :]
+    with span("relu.adder"):
+        return p0[..., w - 1, :] ^ g_map[w - 2]
 
 
 def _shift_planes_dyn(x: jax.Array, d) -> jax.Array:
@@ -225,35 +264,41 @@ def _adder_msb_scan(xw, yw, triples: beaver.ReluTriples, comm, w: int):
     A scan body fires Python-side comm bookkeeping only once (at trace
     time); ``CoalescingComm.note_rounds`` accounts the remaining L-1
     uniform rounds so measured counters still equal ``schedule.simulate``.
+    Its device ops carry the name scope ``relu.adder``.
     """
     from repro.kernels import ref as kref
 
-    p0 = xw ^ yw
-    if w == 1:
-        return p0[..., 0, :]
-    L = beaver.n_levels(w)
-    g = and_open(xw, yw, triples.bin_init, comm)
-    sel = _sel_mask(comm, xw)
-    shifts = jnp.left_shift(jnp.int32(1), jnp.arange(L, dtype=jnp.int32))
+    with jax.named_scope("relu.adder"):
+        p0 = xw ^ yw
+        if w == 1:
+            return p0[..., 0, :]
+        L = beaver.n_levels(w)
+        g = and_open(xw, yw, triples.bin_init, comm)
+        sel = _sel_mask(comm, xw)
+        shifts = jnp.left_shift(jnp.int32(1),
+                                jnp.arange(L, dtype=jnp.int32))
 
-    def level(carry, xs):
-        g, p = carry
-        d_lvl, tri = xs
-        lhs = jnp.concatenate([p, p], axis=-2)
-        rhs = jnp.concatenate([_shift_planes_dyn(g, d_lvl),
-                               _shift_planes_dyn(p, d_lvl)], axis=-2)
-        d_half = lhs ^ tri.a
-        e_half = rhs ^ tri.b
-        opened = comm.swap(jnp.stack([d_half, e_half], axis=1))  # one round
-        g2, p2 = kref.ks_combine(d_half, opened[:, 0], e_half, opened[:, 1],
-                                 tri.a, tri.b, tri.c, sel, g)
-        return (g2, p2), None
+        def level(carry, xs):
+            g, p = carry
+            d_lvl, tri = xs
+            lhs = jnp.concatenate([p, p], axis=-2)
+            rhs = jnp.concatenate([_shift_planes_dyn(g, d_lvl),
+                                   _shift_planes_dyn(p, d_lvl)], axis=-2)
+            d_half = lhs ^ tri.a
+            e_half = rhs ^ tri.b
+            # one round
+            opened = comm.swap(jnp.stack([d_half, e_half], axis=1))
+            g2, p2 = kref.ks_combine(d_half, opened[:, 0], e_half,
+                                     opened[:, 1], tri.a, tri.b, tri.c, sel,
+                                     g)
+            return (g2, p2), None
 
-    (g, _p), _ = jax.lax.scan(level, (g, p0), (shifts, triples.bin_levels))
-    note = getattr(comm, "note_rounds", None)
-    if note is not None:
-        note(L - 1)
-    return p0[..., w - 1, :] ^ g[..., w - 2, :]
+        (g, _p), _ = jax.lax.scan(level, (g, p0),
+                                  (shifts, triples.bin_levels))
+        note = getattr(comm, "note_rounds", None)
+        if note is not None:
+            note(L - 1)
+        return p0[..., w - 1, :] ^ g[..., w - 2, :]
 
 
 def adder_msb(xw: jax.Array, yw: jax.Array, triples: beaver.ReluTriples,
@@ -280,24 +325,27 @@ def _a2b_prepare_rounds(key, v_packed: jax.Array, comm):
     # ``comm.party_slice`` — identity on the sim backend (local party dim
     # is already all parties), the local shard on the mesh backend.  The
     # masks are therefore bit-identical across backends by construction.
-    full = jax.random.bits(key, (comm.n_parties,) + v_packed.shape[1:],
-                           dtype=_U32)
-    # The mask is data-independent, so a compiler may hoist its exchange
-    # ahead of earlier rounds.  XOR-ing in ``s & ~s`` (always 0, not folded
-    # by XLA) makes it depend on the value it masks: the compiled program
-    # keeps the schedule's round order.  ``lax.optimization_barrier`` does
-    # not: XLA removes it before scheduling, and the exchange is hoisted
-    # again (the HLO census tests catch either regression).
-    r = comm.party_slice(full)
-    if v_packed.size:
-        s = v_packed.reshape(-1)[0]
-        r = r ^ (s & ~s)
-    masked = v_packed ^ r
+    with span("relu.a2b"):
+        full = jax.random.bits(key, (comm.n_parties,) + v_packed.shape[1:],
+                               dtype=_U32)
+        # The mask is data-independent, so a compiler may hoist its
+        # exchange ahead of earlier rounds.  XOR-ing in ``s & ~s`` (always
+        # 0, not folded by XLA) makes it depend on the value it masks: the
+        # compiled program keeps the schedule's round order.
+        # ``lax.optimization_barrier`` does not: XLA removes it before
+        # scheduling, and the exchange is hoisted again (the HLO census
+        # tests catch either regression).
+        r = comm.party_slice(full)
+        if v_packed.size:
+            s = v_packed.reshape(-1)[0]
+            r = r ^ (s & ~s)
+        masked = v_packed ^ r
     other_mask = yield r
-    p0 = comm.party_is(0, v_packed)
-    x0_shares = jnp.where(p0, masked, other_mask)   # shares of party0's value
-    x1_shares = jnp.where(p0, other_mask, masked)   # shares of party1's value
-    return x0_shares, x1_shares
+    with span("relu.a2b"):
+        p0 = comm.party_is(0, v_packed)
+        x0_shares = jnp.where(p0, masked, other_mask)   # party0's value
+        x1_shares = jnp.where(p0, other_mask, masked)   # party1's value
+        return x0_shares, x1_shares
 
 
 def a2b_prepare(key, v_packed: jax.Array, comm) -> Tuple[jax.Array, jax.Array]:
@@ -312,19 +360,23 @@ def a2b_prepare(key, v_packed: jax.Array, comm) -> Tuple[jax.Array, jax.Array]:
 # ---------------------------------------------------------------------------
 
 def _beaver_mul_rounds(x: ring.Ring64, y: ring.Ring64,
-                       triple: beaver.ArithTriple, comm):
-    e = ring.sub(x, triple.a)
-    f = ring.sub(y, triple.b)
-    ef = ring.Ring64(jnp.stack([e.lo, f.lo], 1), jnp.stack([e.hi, f.hi], 1))
+                       triple: beaver.ArithTriple, comm,
+                       span_name: str = "relu.mult"):
+    with span(span_name):
+        e = ring.sub(x, triple.a)
+        f = ring.sub(y, triple.b)
+        ef = ring.Ring64(jnp.stack([e.lo, f.lo], 1),
+                         jnp.stack([e.hi, f.hi], 1))
     other = yield ef                                 # single exchange
-    e_open = ring.add(e, ring.Ring64(other.lo[:, 0], other.hi[:, 0]))
-    f_open = ring.add(f, ring.Ring64(other.lo[:, 1], other.hi[:, 1]))
-    z = ring.add(triple.c,
-                 ring.add(ring.mul(e_open, triple.b), ring.mul(f_open, triple.a)))
-    p0 = comm.party_is(0, z.lo)
-    corr = ring.mul(e_open, f_open)
-    return ring.Ring64(jnp.where(p0, ring.add(z, corr).lo, z.lo),
-                       jnp.where(p0, ring.add(z, corr).hi, z.hi))
+    with span(span_name):
+        e_open = ring.add(e, ring.Ring64(other.lo[:, 0], other.hi[:, 0]))
+        f_open = ring.add(f, ring.Ring64(other.lo[:, 1], other.hi[:, 1]))
+        z = ring.add(triple.c, ring.add(ring.mul(e_open, triple.b),
+                                        ring.mul(f_open, triple.a)))
+        p0 = comm.party_is(0, z.lo)
+        corr = ring.mul(e_open, f_open)
+        return ring.Ring64(jnp.where(p0, ring.add(z, corr).lo, z.lo),
+                           jnp.where(p0, ring.add(z, corr).hi, z.hi))
 
 
 def beaver_mul(x: ring.Ring64, y: ring.Ring64, triple: beaver.ArithTriple,
@@ -401,14 +453,17 @@ def products_many(specs: Sequence[Tuple[str, ring.Ring64, ring.Ring64,
 # ---------------------------------------------------------------------------
 
 def _b2a_bit_rounds(bits: jax.Array, triple: beaver.ArithTriple, comm):
-    zeros = jnp.zeros_like(bits)
-    p0 = comm.party_is(0, bits)
-    x = ring.Ring64(jnp.where(p0, bits, zeros), zeros)
-    y = ring.Ring64(jnp.where(p0, zeros, bits), zeros)
-    xy = yield from _beaver_mul_rounds(x, y, triple, comm)
-    s = ring.add(ring.Ring64(bits, zeros), ring.neg(ring.lshift(xy, 1)))
-    # NB: x + y == (b0, b1) == Ring64(bits, 0) summed across parties
-    return s
+    with span("relu.b2a"):
+        zeros = jnp.zeros_like(bits)
+        p0 = comm.party_is(0, bits)
+        x = ring.Ring64(jnp.where(p0, bits, zeros), zeros)
+        y = ring.Ring64(jnp.where(p0, zeros, bits), zeros)
+    xy = yield from _beaver_mul_rounds(x, y, triple, comm,
+                                       span_name="relu.b2a")
+    with span("relu.b2a"):
+        s = ring.add(ring.Ring64(bits, zeros), ring.neg(ring.lshift(xy, 1)))
+        # NB: x + y == (b0, b1) == Ring64(bits, 0) summed across parties
+        return s
 
 
 def b2a_bit(bits: jax.Array, triple: beaver.ArithTriple, comm) -> ring.Ring64:
@@ -428,23 +483,26 @@ def _drelu_rounds(key, x: ring.Ring64, triples: beaver.ReluTriples, comm,
                   k: int, m: int, cone: bool):
     w = k - m
     n = x.shape[-1]
-    if w <= 32:
-        v = ring.extract_bits(x, k, m)              # (P, E) uint32, local
-        planes = ring.bitplanes_u32(v, w)           # (w, P, E)
-    else:
-        planes = ring.extract_planes(x, k, m)       # (w, P, E)
-    planes = jnp.moveaxis(planes, 0, 1)             # (P, w, E)
-    packed = shares.pack_bits(planes)               # (P, w, W)
+    with span("relu.a2b"):
+        if w <= 32:
+            v = ring.extract_bits(x, k, m)          # (P, E) uint32, local
+            planes = ring.bitplanes_u32(v, w)       # (w, P, E)
+        else:
+            planes = ring.extract_planes(x, k, m)   # (w, P, E)
+        planes = jnp.moveaxis(planes, 0, 1)         # (P, w, E)
+        packed = shares.pack_bits(planes)           # (P, w, W)
     x0s, x1s = yield from _a2b_prepare_rounds(key, packed, comm)    # 1 round
     sign_packed = yield from _adder_msb_rounds(x0s, x1s, triples, comm, w,
                                                cone)
-    sign_bits = shares.unpack_bits(sign_packed, n)  # (P, E)
+    with span("relu.b2a"):
+        sign_bits = shares.unpack_bits(sign_packed, n)  # (P, E)
     s = yield from _b2a_bit_rounds(sign_bits, triples.b2a, comm)    # 1 round
-    one = ring.from_int32(jnp.ones((), jnp.int32))
-    p0 = comm.party_is(0, s.lo)
-    d = ring.Ring64(jnp.where(p0, ring.sub(one, s).lo, ring.neg(s).lo),
-                    jnp.where(p0, ring.sub(one, s).hi, ring.neg(s).hi))
-    return d
+    with span("relu.b2a"):
+        one = ring.from_int32(jnp.ones((), jnp.int32))
+        p0 = comm.party_is(0, s.lo)
+        d = ring.Ring64(jnp.where(p0, ring.sub(one, s).lo, ring.neg(s).lo),
+                        jnp.where(p0, ring.sub(one, s).hi, ring.neg(s).hi))
+        return d
 
 
 def drelu(key, x: ring.Ring64, triples: beaver.ReluTriples, comm,
@@ -489,29 +547,35 @@ def relu_scan(key, x: ring.Ring64, triples: beaver.ReluTriples, comm,
     the whole call is therefore one XLA program whose round structure
     matches ``schedule.stream_timeline`` exactly.  The cone-pruned adder
     keeps its static per-level layout (ragged positions cannot scan) but
-    still traces straight through jit as unrolled rounds.
+    still traces straight through jit as unrolled rounds.  Each phase's
+    device ops carry the name scope of its host span (``relu.a2b``,
+    ``relu.adder``, ``relu.b2a``, ``relu.mult``).
     """
     w = k - m
     n = x.shape[-1]
-    if w <= 32:
-        v = ring.extract_bits(x, k, m)
-        planes = ring.bitplanes_u32(v, w)
-    else:
-        planes = ring.extract_planes(x, k, m)
-    planes = jnp.moveaxis(planes, 0, 1)
-    packed = shares.pack_bits(planes)
-    x0s, x1s = a2b_prepare(key, packed, comm)                       # 1 round
+    with jax.named_scope("relu.a2b"):
+        if w <= 32:
+            v = ring.extract_bits(x, k, m)
+            planes = ring.bitplanes_u32(v, w)
+        else:
+            planes = ring.extract_planes(x, k, m)
+        planes = jnp.moveaxis(planes, 0, 1)
+        packed = shares.pack_bits(planes)
+        x0s, x1s = a2b_prepare(key, packed, comm)                   # 1 round
     if cone:
-        sign_packed = adder_msb(x0s, x1s, triples, comm, w, cone=True)
+        with jax.named_scope("relu.adder"):
+            sign_packed = adder_msb(x0s, x1s, triples, comm, w, cone=True)
     else:
         sign_packed = _adder_msb_scan(x0s, x1s, triples, comm, w)
-    sign_bits = shares.unpack_bits(sign_packed, n)
-    s = b2a_bit(sign_bits, triples.b2a, comm)                       # 1 round
-    one = ring.from_int32(jnp.ones((), jnp.int32))
-    p0 = comm.party_is(0, s.lo)
-    d = ring.Ring64(jnp.where(p0, ring.sub(one, s).lo, ring.neg(s).lo),
-                    jnp.where(p0, ring.sub(one, s).hi, ring.neg(s).hi))
-    return beaver_mul(x, d, triples.mult, comm)                     # 1 round
+    with jax.named_scope("relu.b2a"):
+        sign_bits = shares.unpack_bits(sign_packed, n)
+        s = b2a_bit(sign_bits, triples.b2a, comm)                   # 1 round
+        one = ring.from_int32(jnp.ones((), jnp.int32))
+        p0 = comm.party_is(0, s.lo)
+        d = ring.Ring64(jnp.where(p0, ring.sub(one, s).lo, ring.neg(s).lo),
+                        jnp.where(p0, ring.sub(one, s).hi, ring.neg(s).hi))
+    with jax.named_scope("relu.mult"):
+        return beaver_mul(x, d, triples.mult, comm)                 # 1 round
 
 
 def relu_many(keys, xs: Sequence[ring.Ring64],

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.spans import span
+
 from . import beaver, comm as comm_lib, fixed, gmw, ring, ring_linalg, shares
 from .hummingbird import HBLayer
 
@@ -63,40 +65,52 @@ class MPCTensor:
         assert self.frac_bits == other.frac_bits
         return MPCTensor(ring.sub(self.data, other.data), self.frac_bits)
 
+    # The linear layers on public weights below run inside a ``linear``
+    # host span, with ``linear.encode`` (the weights to fixed point),
+    # ``linear.matmul`` (the ring product) and ``linear.truncate`` under it.
+
     def add_public(self, b_f, comm=None) -> "MPCTensor":
         """Add a public constant: only party 0 adds it to its share."""
-        comm = comm or comm_lib.SimComm()
-        enc = fixed.encode(jnp.broadcast_to(jnp.asarray(b_f, jnp.float32),
-                                            self.shape), self.frac_bits)
-        p0 = comm.party_is(0, self.data.lo)
-        zero = ring.zeros(self.shape)
-        lo = jnp.where(p0, enc.lo, zero.lo)
-        hi = jnp.where(p0, enc.hi, zero.hi)
-        return MPCTensor(ring.add(self.data, ring.Ring64(lo, hi)), self.frac_bits)
+        with span("linear"):
+            comm = comm or comm_lib.SimComm()
+            enc = fixed.encode(jnp.broadcast_to(jnp.asarray(b_f, jnp.float32),
+                                                self.shape), self.frac_bits)
+            p0 = comm.party_is(0, self.data.lo)
+            zero = ring.zeros(self.shape)
+            lo = jnp.where(p0, enc.lo, zero.lo)
+            hi = jnp.where(p0, enc.hi, zero.hi)
+            return MPCTensor(ring.add(self.data, ring.Ring64(lo, hi)),
+                             self.frac_bits)
 
     def truncate(self, n: Optional[int] = None) -> "MPCTensor":
         """Fixed-point rescale: arithmetic shift of each signed share
         (SecureML-style local truncation, +-1 LSB error, rare wrap)."""
         n = self.frac_bits if n is None else n
-        return MPCTensor(ring.rshift_arith(self.data, n), self.frac_bits)
+        with span("linear.truncate"):
+            return MPCTensor(ring.rshift_arith(self.data, n), self.frac_bits)
+
+    def _public_product(self, w_f, product) -> "MPCTensor":
+        """``product(shares, encoded weights)``, truncated back."""
+        with span("linear"):
+            with span("linear.encode"):
+                w = encode_weights(w_f, self.frac_bits)
+            with span("linear.matmul"):
+                prod = product(self.data, w)
+            return MPCTensor(prod, self.frac_bits).truncate()
 
     def mul_public(self, c_f) -> "MPCTensor":
-        w = encode_weights(c_f, self.frac_bits)
-        prod = ring.mul(self.data, ring.from_int32(jnp.broadcast_to(w, self.shape)))
-        return MPCTensor(prod, self.frac_bits).truncate()
+        return self._public_product(c_f, lambda x, w: ring.mul(
+            x, ring.from_int32(jnp.broadcast_to(w, self.shape))))
 
     def matmul_public(self, w_f: jax.Array) -> "MPCTensor":
         """x @ W with public float weights [K, N]; local + truncation."""
-        w = encode_weights(w_f, self.frac_bits)
-        prod = ring_linalg.matmul_pub(self.data, w)
-        return MPCTensor(prod, self.frac_bits).truncate()
+        return self._public_product(w_f, ring_linalg.matmul_pub)
 
     def conv2d_public(self, w_f: jax.Array, stride: int = 1,
                       padding: int = 0) -> "MPCTensor":
         """NCHW conv with public float weights [Cout, Cin, kh, kw]."""
-        w = encode_weights(w_f, self.frac_bits)
-        prod = ring_linalg.conv2d_pub(self.data, w, stride, padding)
-        return MPCTensor(prod, self.frac_bits).truncate()
+        return self._public_product(w_f, lambda x, w: ring_linalg.conv2d_pub(
+            x, w, stride, padding))
 
     def avg_pool(self, window: int) -> "MPCTensor":
         """Non-overlapping average pooling on [..., C, H, W] (MPC-friendly
@@ -109,23 +123,25 @@ class MPCTensor:
             a = a.reshape(a.shape[:-2] + (oh, window, ow, window))
             return a
 
-        lo, hi = _pool(self.data.lo), _pool(self.data.hi)
-        acc = ring.zeros(lo.shape[:-4] + (oh, ow))
-        for i in range(window):
-            for j in range(window):
-                acc = ring.add(acc, ring.Ring64(lo[..., :, i, :, j],
-                                                hi[..., :, i, :, j]))
-        summed = MPCTensor(acc, self.frac_bits)
-        return summed.mul_public(1.0 / (window * window))
+        with span("linear"):
+            lo, hi = _pool(self.data.lo), _pool(self.data.hi)
+            acc = ring.zeros(lo.shape[:-4] + (oh, ow))
+            for i in range(window):
+                for j in range(window):
+                    acc = ring.add(acc, ring.Ring64(lo[..., :, i, :, j],
+                                                    hi[..., :, i, :, j]))
+            summed = MPCTensor(acc, self.frac_bits)
+            return summed.mul_public(1.0 / (window * window))
 
     def global_avg_pool(self) -> "MPCTensor":
         """[..., C, H, W] -> [..., C] mean over spatial dims."""
-        h, w = self.shape[-2], self.shape[-1]
-        flat = self.data.reshape(self.data.shape[:-2] + (h * w,))
-        acc = ring.zeros(flat.shape[:-1])
-        for i in range(h * w):
-            acc = ring.add(acc, flat[..., i])
-        return MPCTensor(acc, self.frac_bits).mul_public(1.0 / (h * w))
+        with span("linear"):
+            h, w = self.shape[-2], self.shape[-1]
+            flat = self.data.reshape(self.data.shape[:-2] + (h * w,))
+            acc = ring.zeros(flat.shape[:-1])
+            for i in range(h * w):
+                acc = ring.add(acc, flat[..., i])
+            return MPCTensor(acc, self.frac_bits).mul_public(1.0 / (h * w))
 
     def reshape(self, *shape) -> "MPCTensor":
         return MPCTensor(self.data.reshape((self.data.shape[0],) + tuple(shape)),
@@ -237,10 +253,11 @@ def relu_many(keys, tensors: Sequence["MPCTensor"], comm=None,
             continue
         n = int(np.prod(t.shape))
         if tri is None:
-            kt, key = jax.random.split(key)
-            tri = beaver.gen_relu_triples(kt, n, hb.width,
-                                          n_parties=t.data.shape[0],
-                                          cone=cone)
+            with span("beaver.triples"):
+                kt, key = jax.random.split(key)
+                tri = beaver.gen_relu_triples(kt, n, hb.width,
+                                              n_parties=t.data.shape[0],
+                                              cone=cone)
         flats.append(t.data.reshape((t.data.shape[0], n)))
         run_keys.append(key)
         tris.append(tri)

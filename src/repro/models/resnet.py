@@ -20,6 +20,7 @@ from repro.api import register_mpc_forward
 from repro.configs.resnet import ResNetConfig
 from repro.core import MPCTensor, beaver
 from repro.core.hummingbird import HBConfig
+from repro.runtime.spans import span
 
 
 def _conv_init(key, cout, cin, k):
@@ -188,6 +189,13 @@ def gen_mpc_triples(key, plan, hb: Optional[HBConfig], cfg: ResNetConfig,
         key, [(n, hb_layers[g].width) for n, g in plan], cone=cone)
 
 
+def _fold_bn_linear(conv_w, bn):
+    """``fold_bn`` of the private forward, in a ``linear`` host span: the
+    folding runs on every request, beside the linear layer it feeds."""
+    with span("linear"):
+        return fold_bn(conv_w, bn)
+
+
 def _mpc_forward(params, hs: List[MPCTensor], cfg: ResNetConfig, relu_fn,
                  comm) -> List[MPCTensor]:
     """Shared MPC forward over sibling streams.
@@ -195,7 +203,7 @@ def _mpc_forward(params, hs: List[MPCTensor], cfg: ResNetConfig, relu_fn,
     ``relu_fn(tensors, group) -> tensors`` is invoked once per ReLU point
     with the sibling tensors of every stream, so implementations can share
     protocol rounds across streams (see mpc_apply_many)."""
-    w, b = fold_bn(params["stem"], params["bn_stem"])
+    w, b = _fold_bn_linear(params["stem"], params["bn_stem"])
     hs = [h.conv2d_public(w, 1, 1).add_public(b[:, None, None], comm)
           for h in hs]
     hs = relu_fn(hs, 0)
@@ -203,27 +211,27 @@ def _mpc_forward(params, hs: List[MPCTensor], cfg: ResNetConfig, relu_fn,
         for block in stage:
             stride = 2 if ("proj" in block and si > 0) else 1
             if "conv3" in block:
-                w1, b1 = fold_bn(block["conv1"], block["bn1"])
+                w1, b1 = _fold_bn_linear(block["conv1"], block["bn1"])
                 ys = relu_fn([h.conv2d_public(w1, 1, 0)
                               .add_public(b1[:, None, None], comm)
                               for h in hs], si + 1)
-                w2, b2 = fold_bn(block["conv2"], block["bn2"])
+                w2, b2 = _fold_bn_linear(block["conv2"], block["bn2"])
                 ys = relu_fn([y.conv2d_public(w2, stride, 1)
                               .add_public(b2[:, None, None], comm)
                               for y in ys], si + 1)
-                w3, b3 = fold_bn(block["conv3"], block["bn3"])
+                w3, b3 = _fold_bn_linear(block["conv3"], block["bn3"])
                 ys = [y.conv2d_public(w3, 1, 0)
                       .add_public(b3[:, None, None], comm) for y in ys]
             else:
-                w1, b1 = fold_bn(block["conv1"], block["bn1"])
+                w1, b1 = _fold_bn_linear(block["conv1"], block["bn1"])
                 ys = relu_fn([h.conv2d_public(w1, stride, 1)
                               .add_public(b1[:, None, None], comm)
                               for h in hs], si + 1)
-                w2, b2 = fold_bn(block["conv2"], block["bn2"])
+                w2, b2 = _fold_bn_linear(block["conv2"], block["bn2"])
                 ys = [y.conv2d_public(w2, 1, 1)
                       .add_public(b2[:, None, None], comm) for y in ys]
             if "proj" in block:
-                wp, bp = fold_bn(block["proj"], block["bn_proj"])
+                wp, bp = _fold_bn_linear(block["proj"], block["bn_proj"])
                 hs = [h.conv2d_public(wp, stride, 0)
                       .add_public(bp[:, None, None], comm) for h in hs]
             hs = relu_fn([h + y for h, y in zip(hs, ys)], si + 1)

@@ -52,6 +52,7 @@ from repro.core.mpc_tensor import MPCTensor
 from repro.api.compile import PrivateModel, compile as compile_model
 from repro.api.plan import LAN, NETWORKS, NetworkPreset, Plan, trace_plan
 from repro.api.session import Session
+from repro.runtime.spans import span
 from repro.runtime.watchdog import StragglerWatchdog
 
 
@@ -226,6 +227,7 @@ class BatchReport:
     retries: int = 0                  # batch re-executions on comm faults
     faults_recovered: int = 0         # transport rounds healed by re-send
     shed: int = 0                     # requests deadline-shed at admission
+    execute_s: float = 0.0            # measured wall time of the replay
 
     @property
     def n_requests(self) -> int:
@@ -236,11 +238,9 @@ class BatchReport:
         """Serial-to-fused round ratio: N identical requests approach N."""
         return self.serial_rounds / max(1, self.measured_rounds)
 
-    @property
-    def sim_latencies_s(self) -> Tuple[float, ...]:
-        """Per-request simulated completion latency: queue wait plus the
-        merged batch's schedule-predicted timeline."""
-        return tuple(w + self.predicted_latency_s for w in self.waits_s)
+    def queue_s(self, request_id: int) -> float:
+        """Request ``request_id``'s wait in the queue before execution."""
+        return self.waits_s[self.request_ids.index(request_id)]
 
 
 class InferenceEngine:
@@ -408,6 +408,8 @@ class InferenceEngine:
         the request key).  ``request_id`` defaults to an auto-increment;
         pass an explicit id to make the request's protocol randomness
         independent of submission order (``Session.request_key``).
+        The work after the engine lock is taken runs in an
+        ``engine.submit`` host span.
 
         ``deadline_s`` is a completion budget measured from arrival: at
         execution time a request whose schedule-predicted latency alone
@@ -421,9 +423,10 @@ class InferenceEngine:
         batch formation only ever sees cache hits and can never drop
         already-queued requests on a trace error.
         """
-        with self._lock:
+        with self._lock, span("engine.submit") as sp:
             if request_id is None:
                 request_id = self._next_id
+            sp.set_metadata(request_id=request_id)
             if request_id in self._used_ids:
                 raise errors.DuplicateRequest(
                     f"request id {request_id} already submitted")
@@ -611,10 +614,12 @@ class InferenceEngine:
                 xs = self.on_batch_attempt(admitted)
             t0 = time.monotonic()
             try:
-                outs = self.model._run_streams(
-                    xs, key_iters, providers,
-                    self.comm, self.model.params,
-                    auto_batch=self.policy.merge_identical)
+                with span("engine.execute",
+                          request_ids=tuple(r.id for r in admitted)):
+                    outs = self.model._run_streams(
+                        xs, key_iters, providers,
+                        self.comm, self.model.params,
+                        auto_batch=self.policy.merge_identical)
                 break
             except BaseException as e:
                 for p, tok in tokens:
@@ -657,7 +662,8 @@ class InferenceEngine:
             waits_s=tuple(max(0.0, now_s - r.arrival_s) for r in admitted),
             retries=attempts,
             faults_recovered=faults_recovered,
-            shed=shed)
+            shed=shed,
+            execute_s=wall)
         self.reports.append(report)
         self._totals["requests"] += report.n_requests
         self._totals["batches"] += 1
@@ -732,24 +738,26 @@ class InferenceEngine:
     # -- aggregate stats -------------------------------------------------------
     def stats(self) -> Dict[str, float]:
         """Lifetime totals (fused vs serial rounds over every executed
-        batch) plus the simulated per-request latency distribution (queue
-        wait + the merged timeline under ``policy.network``) over the
-        retained ``report_history`` window."""
+        batch) plus measured percentiles over the retained
+        ``report_history`` window: each request's queue wait, and each
+        batch's execute wall time (the replay, host clock)."""
         with self._lock:
-            lats = sorted(l for rep in self.reports
-                          for l in rep.sim_latencies_s)
+            waits = sorted(w for rep in self.reports for w in rep.waits_s)
+            execs = sorted(rep.execute_s for rep in self.reports)
             totals = dict(self._totals)
 
-        def pct(p: float) -> float:
-            if not lats:
+        def pct(values: List[float], p: float) -> float:
+            if not values:
                 return 0.0
-            return lats[min(len(lats) - 1, int(p * len(lats)))]
+            return values[min(len(values) - 1, int(p * len(values)))]
 
         return {
             **totals,
             "rounds_saved_ratio": (totals["serial_rounds"]
                                    / max(1, totals["fused_rounds"])),
-            "p50_sim_latency_s": pct(0.50),
-            "p95_sim_latency_s": pct(0.95),
+            "p50_queue_wait_s": pct(waits, 0.50),
+            "p95_queue_wait_s": pct(waits, 0.95),
+            "p50_execute_s": pct(execs, 0.50),
+            "p95_execute_s": pct(execs, 0.95),
             "slow_batches": len(self.watchdog.stragglers),
         }

@@ -11,10 +11,10 @@ manual overrides).  HTTP parsing is hand-rolled over asyncio streams
 Routes (all JSON):
 
 - ``POST /infer``  body ``{"tenant": str, "x": nested-list, optional
-  "request_id", "deadline_s", "timeout_s"}`` -> ``{"id", "y",
-  "batch": {rounds, requests, wall-queue stats}}``.  The input is
-  secret-shared inside ``submit`` (the frontend process is the client
-  gateway) and the revealed output returned.
+  "request_id", "deadline_s", "timeout_s"}`` -> ``{"id", "y", "wall_s",
+  "batch": {rounds, bytes, requests, "queue_s", "execute_s"}}``.  The
+  input is secret-shared inside ``submit`` (the frontend process is the
+  client gateway) and the revealed output returned.
 - ``GET /healthz`` liveness: queue depth, pump state, last pump error.
 - ``GET /stats``   ``engine.stats()`` plus — when the engine session
   came from ``Session.connect`` — the socket transport's wire counters
@@ -52,6 +52,7 @@ import numpy as np
 
 from repro import errors
 from repro.core import comm as comm_lib
+from repro.runtime.spans import span
 
 _MAX_BODY = 64 << 20          # 64 MiB request cap (a batch of images is MBs)
 
@@ -80,6 +81,10 @@ class Frontend:
     # -- request handling ------------------------------------------------------
     def _infer_blocking(self, payload: Dict) -> Dict:
         """Runs on a worker thread: submit, wait on the pump, reveal."""
+        with span("frontend.infer"):
+            return self._infer(payload)
+
+    def _infer(self, payload: Dict) -> Dict:
         if "x" not in payload:
             raise ValueError("body must carry 'x' (nested list input)")
         x = np.asarray(payload["x"], dtype=np.float32)
@@ -88,20 +93,26 @@ class Frontend:
             request_id=payload.get("request_id"),
             deadline_s=payload.get("deadline_s"))
         t0 = time.monotonic()
-        out = fut.result(timeout_s=float(payload.get(
-            "timeout_s", self.result_timeout_s)))
+        with span("frontend.wait"):
+            out = fut.result(timeout_s=float(payload.get(
+                "timeout_s", self.result_timeout_s)))
+        with span("frontend.reveal"):
+            y = np.asarray(out.reveal()).tolist()
         resp = {"id": fut.request.id,
                 "tenant": fut.request.tenant,
-                "y": np.asarray(out.reveal()).tolist(),
+                "y": y,
                 "wall_s": time.monotonic() - t0}
-        if fut.report is not None:
+        report = fut.report
+        if report is not None:
             resp["batch"] = {
-                "n_requests": fut.report.n_requests,
-                "measured_rounds": fut.report.measured_rounds,
-                "predicted_rounds": fut.report.predicted_rounds,
-                "measured_bytes": fut.report.measured_bytes,
-                "rounds_saved_ratio": fut.report.rounds_saved_ratio,
-                "retries": fut.report.retries,
+                "n_requests": report.n_requests,
+                "measured_rounds": report.measured_rounds,
+                "predicted_rounds": report.predicted_rounds,
+                "measured_bytes": report.measured_bytes,
+                "rounds_saved_ratio": report.rounds_saved_ratio,
+                "retries": report.retries,
+                "queue_s": report.queue_s(fut.request.id),
+                "execute_s": report.execute_s,
             }
         return resp
 
@@ -142,7 +153,8 @@ class Frontend:
             status, body = 400, {"error": f"{type(e).__name__}: {e}"}
         except Exception as e:                     # noqa: BLE001 — last line
             status, body = 500, {"error": f"{type(e).__name__}: {e}"}
-        payload = json.dumps(body).encode()
+        with span("frontend.encode"):
+            payload = json.dumps(body).encode()
         reason = {200: "OK", 400: "Bad Request", 404: "Not Found",
                   500: "Internal Server Error",
                   504: "Gateway Timeout"}.get(status, "")
@@ -184,8 +196,9 @@ class Frontend:
             if n > _MAX_BODY:
                 return 400, {"error": f"body of {n} bytes exceeds the "
                              f"{_MAX_BODY} byte cap"}
-            payload = json.loads((await reader.readexactly(n)).decode()
-                                 if n else "{}")
+            raw = await reader.readexactly(n) if n else b"{}"
+            with span("frontend.decode"):
+                payload = json.loads(raw.decode())
             resp = await asyncio.to_thread(self._infer_blocking, payload)
             self.requests_served += 1
             return 200, resp

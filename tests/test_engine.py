@@ -758,3 +758,26 @@ def test_typed_errors_preserve_builtin_contracts(tiny):
         class Unknown:
             pass
         api.compile(None, {}, Unknown(), plan, api.Session(key=0))
+
+
+def test_stats_report_measured_queue_wait_and_execute(tiny):
+    """``stats()`` percentiles are measured: each request's queue wait
+    (arrival to execution) and each batch's replay wall time."""
+    import time
+
+    params, plan = tiny
+    engine = _engine(params, plan)
+    now = time.monotonic()
+    f1 = engine.submit("alice", _request_tensor(0, 2), arrival_s=now - 2.0)
+    f2 = engine.submit("bob", _request_tensor(1, 2), arrival_s=now - 1.0)
+    engine.flush()
+    rep = engine.reports[-1]
+    assert rep.request_ids == (f1.request.id, f2.request.id)
+    assert rep.queue_s(f1.request.id) >= 2.0
+    assert 1.0 <= rep.queue_s(f2.request.id) < rep.queue_s(f1.request.id)
+    assert rep.execute_s > 0.0
+    stats = engine.stats()
+    assert stats["p95_queue_wait_s"] == rep.queue_s(f1.request.id)
+    assert stats["p50_queue_wait_s"] == rep.queue_s(f1.request.id)
+    assert stats["p50_execute_s"] == stats["p95_execute_s"] == rep.execute_s
+    assert not any("sim" in k for k in stats)
