@@ -90,7 +90,9 @@ class MPCTensor:
             return MPCTensor(ring.rshift_arith(self.data, n), self.frac_bits)
 
     def _public_product(self, w_f, product) -> "MPCTensor":
-        """``product(shares, encoded weights)``, truncated back."""
+        """``product(shares, encoded weights)``, truncated back.  The
+        products are ``ring_linalg``'s compiled programs, so
+        ``linear.matmul`` bounds one dispatch."""
         with span("linear"):
             with span("linear.encode"):
                 w = encode_weights(w_f, self.frac_bits)
@@ -99,8 +101,7 @@ class MPCTensor:
             return MPCTensor(prod, self.frac_bits).truncate()
 
     def mul_public(self, c_f) -> "MPCTensor":
-        return self._public_product(c_f, lambda x, w: ring.mul(
-            x, ring.from_int32(jnp.broadcast_to(w, self.shape))))
+        return self._public_product(c_f, ring_linalg.mul_pub)
 
     def matmul_public(self, w_f: jax.Array) -> "MPCTensor":
         """x @ W with public float weights [K, N]; local + truncation."""

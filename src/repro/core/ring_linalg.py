@@ -10,8 +10,19 @@ explicit VMEM blocking.
 int32 accumulation safety: |sum_s| <= pairs(s) * K * 128 * 128 with
 pairs(s) <= 5, so K <= 2^31 / (5 * 2^14) = 26214 per chunk; larger K is
 chunked and the partial results are added in the ring.
+
+The products by public weights (``conv2d_pub``, ``matmul_pub``,
+``mul_pub``) each run as one compiled program per operand shapes (and
+conv stride and padding): eager, they are hundreds of small dispatches.
+The weights are arguments, never constants of the program, so new
+weights reuse it.  ``program_counts()`` says how often they were called
+and how many programs were traced; ``__wrapped__`` is the eager function.
 """
 from __future__ import annotations
+
+import functools
+import threading
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -51,6 +62,42 @@ def _matmul_chunk(x: ring.Ring64, w_i32: jax.Array) -> ring.Ring64:
     return out
 
 
+_counts_lock = threading.Lock()
+_counts = {"calls": 0, "compiled": 0}
+
+
+def _count(key: str) -> None:
+    with _counts_lock:
+        _counts[key] += 1
+
+
+def program_counts() -> Dict[str, int]:
+    """Process totals of the compiled public-weight products: ``calls``
+    (every call, traced ones included) and ``compiled`` (programs traced:
+    jit cache misses)."""
+    with _counts_lock:
+        return dict(_counts)
+
+
+def _compiled(fn, static_argnames=()):
+    """``fn`` as one jitted program per operand shapes, counted."""
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        _count("compiled")          # runs only while jit traces
+        return fn(*args, **kwargs)
+
+    jitted = jax.jit(traced, static_argnames=static_argnames)
+
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        _count("calls")
+        return jitted(*args, **kwargs)
+
+    return call
+
+
+@_compiled
 def matmul_pub(x: ring.Ring64, w_i32: jax.Array) -> ring.Ring64:
     """mod-2^64 matmul of ring values by public int32 weights.
 
@@ -67,6 +114,13 @@ def matmul_pub(x: ring.Ring64, w_i32: jax.Array) -> ring.Ring64:
         part = _matmul_chunk(x[..., start:end], w_i32[start:end])
         out = part if out is None else ring.add(out, part)
     return out
+
+
+@_compiled
+def mul_pub(x: ring.Ring64, w_i32: jax.Array) -> ring.Ring64:
+    """Elementwise mod-2^64 product of ring values by public int32 weights
+    that broadcast to ``x``'s shape."""
+    return ring.mul_pub(x, jnp.broadcast_to(w_i32, x.shape))
 
 
 # Secret x secret: both operands decompose to 8 planes, so pairs(s) <= 8
@@ -147,6 +201,7 @@ def im2col(x: ring.Ring64, kh: int, kw: int, stride: int = 1,
     return ring.Ring64(_one(x.lo), _one(x.hi))
 
 
+@functools.partial(_compiled, static_argnames=("stride", "padding"))
 def conv2d_pub(x: ring.Ring64, w_i32: jax.Array, stride: int = 1,
                padding: int = 0) -> ring.Ring64:
     """Ring64 [..., C, H, W] conv by public int32 [Cout, C, kh, kw]."""
@@ -156,6 +211,6 @@ def conv2d_pub(x: ring.Ring64, w_i32: jax.Array, stride: int = 1,
     ow = (w + 2 * padding - kw) // stride + 1
     patches = im2col(x, kh, kw, stride, padding)        # (..., OH*OW, C*kh*kw)
     wmat = w_i32.reshape(cout, cin * kh * kw).T          # (C*kh*kw, Cout)
-    out = matmul_pub(patches, wmat)                      # (..., OH*OW, Cout)
+    out = matmul_pub.__wrapped__(patches, wmat)          # (..., OH*OW, Cout)
     out = ring.Ring64(jnp.moveaxis(out.lo, -1, -2), jnp.moveaxis(out.hi, -1, -2))
     return out.reshape(out.shape[:-1] + (oh, ow))

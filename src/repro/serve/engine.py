@@ -47,7 +47,8 @@ import jax
 import jax.numpy as jnp
 
 from repro import errors
-from repro.core import beaver, comm as comm_lib, ring, schedule as schedule_lib
+from repro.core import beaver, comm as comm_lib, ring, ring_linalg, \
+    schedule as schedule_lib
 from repro.core.mpc_tensor import MPCTensor
 from repro.api.compile import PrivateModel, compile as compile_model
 from repro.api.plan import LAN, NETWORKS, NetworkPreset, Plan, trace_plan
@@ -740,11 +741,15 @@ class InferenceEngine:
         """Lifetime totals (fused vs serial rounds over every executed
         batch) plus measured percentiles over the retained
         ``report_history`` window: each request's queue wait, and each
-        batch's execute wall time (the replay, host clock)."""
+        batch's execute wall time (the replay, host clock).
+        ``linear_program_calls`` / ``linear_programs_compiled`` are the
+        process's calls of ``ring_linalg``'s compiled public-weight
+        products and the programs traced for them."""
         with self._lock:
             waits = sorted(w for rep in self.reports for w in rep.waits_s)
             execs = sorted(rep.execute_s for rep in self.reports)
             totals = dict(self._totals)
+        linear = ring_linalg.program_counts()
 
         def pct(values: List[float], p: float) -> float:
             if not values:
@@ -760,4 +765,6 @@ class InferenceEngine:
             "p50_execute_s": pct(execs, 0.50),
             "p95_execute_s": pct(execs, 0.95),
             "slow_batches": len(self.watchdog.stragglers),
+            "linear_program_calls": linear["calls"],
+            "linear_programs_compiled": linear["compiled"],
         }
